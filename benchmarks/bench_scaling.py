@@ -1,8 +1,10 @@
 """Scaling benchmarks — the paper's #cores axis mapped to mesh devices.
 
 Runs build + query on 1/2/4/8 fake CPU devices in subprocesses (device
-count is fixed at jax init).  One physical core backs all fake devices, so
-WALL TIME cannot drop; what the bench verifies and reports is
+count is fixed at jax init); it refuses to start where JAX's backend is
+not the CPU, since a parent holding a chip locks its children out.  One
+physical core backs all fake devices, so WALL TIME cannot drop; what the
+bench verifies and reports is
   * exactness under sharding (answers == oracle at every device count),
   * work partitioning (per-shard refined-series counts, max/mean skew —
     the paper's load-balancing concern),
@@ -83,9 +85,21 @@ print(json.dumps({
 
 
 def run(device_counts=(1, 2, 4, 8)) -> list[dict]:
+    import jax
+    if jax.default_backend() != "cpu":
+        # the children below need the accelerator this process now holds
+        # (one process per chip), and fake host devices would measure
+        # nothing there anyway
+        raise SystemExit(
+            f"bench_scaling runs the sharded protocol on fake CPU devices "
+            f"in child processes; this process holds the "
+            f"{jax.default_backend()!r} backend, so it refuses to run. "
+            "On a chip host, measure search_sharded in one process over "
+            "jax.devices().")
     rows = []
     for n in device_counts:
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
         env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                          "src")

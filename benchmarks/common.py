@@ -52,6 +52,8 @@ class BenchRunner:
     def main(self, run: Callable[[argparse.Namespace], list[dict]],
              argv=None) -> int:
         args = self.ap.parse_args(argv)
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
         rows = run(args)
         if args.out:
             with open(args.out, "w") as f:

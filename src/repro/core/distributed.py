@@ -41,7 +41,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import repro.core.index as index_lib
-from repro.compat import shard_map
 from repro.core import engine
 from repro.core import frontier as frontier_lib
 from repro.core.frontier import Frontier
@@ -91,8 +90,10 @@ def build_sharded(raw: jax.Array, mesh: Mesh, *, w: int = 16, card: int = 256,
 
     out_specs = index_pspecs(mesh, n=n, w=w, card=card, capacity=cap,
                              n_real=shard_n)
-    fn = shard_map(_build, mesh=mesh, in_specs=(P(ax), P(ax)),
-                       out_specs=out_specs)
+    # check_vma off: the Pallas summarize kernel's outputs carry no
+    # varying-axes annotation, which the check would reject on the chip
+    fn = jax.shard_map(_build, mesh=mesh, in_specs=(P(ax), P(ax)),
+                       out_specs=out_specs, check_vma=False)
     return fn(raw, ids)
 
 
@@ -153,7 +154,7 @@ def search_sharded(sharded_index: BlockIndex, queries: jax.Array, mesh: Mesh,
         dist=P(None), idx=P(None),
         stats=SearchStats(blocks_visited=P(None), series_refined=P(None),
                           lb_series=P(None), iters=P()))
-    fn = shard_map(_search, mesh=mesh, in_specs=(specs, P(None)),
+    fn = jax.shard_map(_search, mesh=mesh, in_specs=(specs, P(None)),
                        out_specs=out, check_vma=False)
     return fn(sharded_index, queries)
 
@@ -246,7 +247,7 @@ def search_sharded_scan(raw: jax.Array, queries: jax.Array, mesh: Mesh,
                               ids=local_ids)
         return _merge_shards(res, ax)
 
-    fn = shard_map(_scan, mesh=mesh, in_specs=(P(ax), P(ax), P(None)),
+    fn = jax.shard_map(_scan, mesh=mesh, in_specs=(P(ax), P(ax), P(None)),
                        out_specs=(P(None), P(None)), check_vma=False)
     dist, idx = fn(raw, ids, queries)
     qn = queries.shape[0]

@@ -196,7 +196,10 @@ def query_block_l2(q: jax.Array, blocks: jax.Array) -> jax.Array:
     """
     qq = jnp.sum(q * q, axis=-1)                              # (Q,)
     xx = jnp.sum(blocks * blocks, axis=-1)                    # (Q, ..., C)
-    cross = jnp.einsum("qn,q...n->q...", q, blocks)
+    # HIGHEST for the same reason as kernels/batch_l2.py: a single bf16
+    # MXU pass would err by ~1e-1, enough to reorder near neighbours
+    cross = jnp.einsum("qn,q...n->q...", q, blocks,
+                       precision=jax.lax.Precision.HIGHEST)
     extra = xx.ndim - 1
     qq = qq.reshape(qq.shape + (1,) * extra)
     return jnp.maximum(qq + xx - 2.0 * cross, 0.0)

@@ -147,7 +147,17 @@ def interleaved_keys(sax: jax.Array, w: int = W, bits: int = 8) -> tuple[jax.Arr
 
 
 def sort_order(sax: jax.Array, w: int = W, bits: int = 8) -> jax.Array:
-    """Permutation sorting series by their bit-interleaved iSAX word."""
+    """Permutation sorting series by their bit-interleaved iSAX word.
+
+    Ties keep input order, as ``jnp.lexsort`` would.  Built as one stable
+    single-key sort per key, least significant first (LSD): the same
+    permutation, but a sort whose comparator spans all four keys took
+    about four minutes in the TPU compiler (v5e target) against about half
+    a minute for these passes.
+    """
     keys = interleaved_keys(sax, w, bits)
-    # jnp.lexsort: last key is the primary one.
-    return jnp.lexsort(tuple(reversed(keys)))
+    order = jnp.arange(sax.shape[0], dtype=jnp.int32)
+    for key in reversed(keys):
+        _, order = jax.lax.sort((key[order], order), num_keys=1,
+                                is_stable=True)
+    return order

@@ -26,8 +26,11 @@ def _kernel(q_ref, x_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)          # (TN, n)
     qq = jnp.sum(q * q, axis=-1, keepdims=True)             # (TQ, 1)
     xx = jnp.sum(x * x, axis=-1)[None, :]                   # (1, TN)
+    # HIGHEST: one bf16 MXU pass (the f32 default) errs by ~1e-1 on the
+    # cross term at n=256, far above the k-NN distance gaps
     cross = jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                 # (TQ, TN) on MXU
     out_ref[...] = jnp.maximum(qq + xx - 2.0 * cross, 0.0)
 

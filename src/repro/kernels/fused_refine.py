@@ -18,14 +18,16 @@ intermediates between them:
 Only (Q, k) candidates and the (Q,) live-lane count ever reach HBM; the
 (Q, C) lower-bound and distance panels never materialize.
 
-Bit-compatibility: the default tile sizes REPLICATE kernels/batch_l2.py
-(tq = min(128, max(8, Q)), tc = min(256, max(128, C)), zero-padded
-operands), so each distance tile is the same dot_general on the same
-values the unfused kernel would run — distances agree bit-for-bit with
-``ops.batch_l2`` in the same mode, and since selection is integer-exact
-and feeding the frontier a top-k subset provably preserves the final
-top-k (``Frontier.insert_topk``), the engine's golden parity suite
-passes unchanged under both ref and interpret dispatch.
+Oracle contract: selection is integer-exact and feeding the frontier a
+top-k subset provably preserves the final top-k
+(``Frontier.insert_topk``), so ids and live counts match the oracle
+exactly.  Distances match within the worst-case f32 error of the
+expanded form, (4 gamma_n + 8u)(||q||^2 + ||x||^2) on squared
+distances (DESIGN.md §8) — not bit-for-bit: the compiler picks a dot's
+summation order by its shapes and fusion context, so two evaluations of
+the same expanded form may differ in the last bits.  The default tile
+sizes still mirror kernels/batch_l2.py (tq = min(128, max(8, Q)),
+tc = min(256, max(128, C))).
 
 Dead lanes come back as (INF, -1) — exactly what the engine's unfused
 path inserted — and callers fold the per-query active mask into ``thr``
@@ -72,8 +74,10 @@ def _kernel(q_ref, qp_ref, thr_ref, x_ref, lo_ref, hi_ref, id_ref,
         x = x_ref[...].astype(jnp.float32)                  # (TC, n)
         qq = jnp.sum(q * q, axis=-1, keepdims=True)         # (TQ, 1)
         xx = jnp.sum(x * x, axis=-1)[None, :]               # (1, TC)
+        # HIGHEST: as in batch_l2, one bf16 pass would err by ~1e-1
         cross = jax.lax.dot_general(
             q, x, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)             # (TQ, TC) on MXU
         d = jnp.maximum(qq + xx - 2.0 * cross, 0.0)
         d = jnp.where(live, d, INF)
@@ -98,7 +102,7 @@ def fused_panel_topk(q: jax.Array, q_paa: jax.Array, block: jax.Array,
     -> (sel_d (Q, k), sel_id (Q, k), n_live (Q,) int32)."""
     qn, w = q_paa.shape
     c = block.shape[0]
-    # batch_l2's tiling rules — the bit-compatibility contract above
+    # batch_l2's tiling rules
     tq = min(tile_q, max(8, qn))
     tc = min(tile_c, max(128, c))
 

@@ -62,7 +62,9 @@ def batch_l2_ref(q: jax.Array, x: jax.Array) -> jax.Array:
     x = x.astype(jnp.float32)
     qq = jnp.sum(q * q, axis=-1, keepdims=True)          # (Q, 1)
     xx = jnp.sum(x * x, axis=-1)[None, :]                # (1, N)
-    cross = q @ x.T                                      # (Q, N) on the MXU
+    # HIGHEST: at the TPU's default f32 precision (one bf16 pass) the
+    # cross term errs by ~1e-1 at n=256, far above the k-NN gaps
+    cross = jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(qq + xx - 2.0 * cross, 0.0)
 
 

@@ -60,8 +60,6 @@ class Roofline:
 
 def analyze(compiled, *, n_chips: int, model_flops: float) -> Roofline:
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):                 # older jax returns [dict]
-        ca = ca[0]
     totals = hlo_analysis.analyze_text(compiled.as_text())
     flops = float(totals.flops)
     bytes_hbm = float(totals.bytes)

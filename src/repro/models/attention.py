@@ -26,7 +26,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 
 NEG = -1.0e30
 
@@ -335,7 +334,7 @@ def decode_attend_seqsharded(q: jax.Array, k_new: jax.Array,
         return out.reshape(bl, 1, h, hd).astype(qf.dtype), kf, vf
 
     cache_spec = P(bp, ax, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bp, None, None, None), P(bp, None, None, None),
                   P(bp, None, None, None), cache_spec, cache_spec, P()),

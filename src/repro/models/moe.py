@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.models import common
 
 
@@ -159,7 +157,7 @@ def moe_ffn_ep(x: jax.Array, params: dict, *, top_k: int,
         return y.reshape(b, s, d), aux
 
     dp = data_axes if len(data_axes) > 1 else data_axes[0]
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None),
                   P(model_axis, None, None), P(model_axis, None, None),

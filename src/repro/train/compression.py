@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def quantize8(g: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -81,7 +80,7 @@ def ddp_allreduce_int8(grads: Any, err: Any, mesh: Mesh,
         return (jax.tree.map(lambda o: o[0], out, is_leaf=leaf),
                 jax.tree.map(lambda o: o[1][None], out, is_leaf=leaf))
 
-    fn = shard_map(all_leaves, mesh=mesh,
+    fn = jax.shard_map(all_leaves, mesh=mesh,
                        in_specs=(P(ax), P(ax)), out_specs=(P(), P(ax)),
                        check_vma=False)
     return fn(grads, err)

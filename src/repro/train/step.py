@@ -49,7 +49,9 @@ def make_train_step(cfg, *, mesh=None, data_axes: tuple[str, ...] = (),
     Signature: (params, opt_state, batch) -> (params, opt_state, metrics).
     Gradients are accumulated in f32 across ``cfg.microbatch`` microbatches
     (a ``lax.scan``, so HLO size is constant in the count); non-finite
-    grads skip the update and bump ``metrics["skipped"]``.
+    grads skip the update and bump ``metrics["skipped"]``.  A ``mesh``
+    needs Auto axes (``repro.launch.mesh.make_mesh``): the model places
+    activations with sharding constraints.
     """
     mb = microbatch if microbatch is not None else max(1, cfg.microbatch)
     kind = cfg.optimizer

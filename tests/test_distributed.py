@@ -142,8 +142,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.models import common, transformer as T
 from repro.train import make_train_step, opt_init
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import param_pspecs
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_config("granite-moe-1b-a400m", smoke=True)
 key = jax.random.PRNGKey(0)
 params = common.build_params(T.param_specs(cfg), key)
@@ -175,7 +176,6 @@ def test_sharded_search_bit_identical_to_noreuse_protocol():
     run_subprocess("""
 import functools, jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.core import distributed, engine
 from repro.core.search import SearchResult, SearchStats
 mesh = jax.make_mesh((8,), ("data",))
@@ -208,8 +208,8 @@ out = SearchResult(dist=P(None), idx=P(None),
                    stats=SearchStats(blocks_visited=P(None),
                                      series_refined=P(None),
                                      lb_series=P(None), iters=P()))
-old = shard_map(_search_noreuse, mesh=mesh, in_specs=(specs, P(None)),
-                out_specs=out, check_vma=False)(sidx, qs)
+old = jax.shard_map(_search_noreuse, mesh=mesh, in_specs=(specs, P(None)),
+                    out_specs=out, check_vma=False)(sidx, qs)
 new = distributed.search_sharded(sidx, qs, mesh, k=k)
 assert np.array_equal(np.asarray(new.idx), np.asarray(old.idx))
 assert np.array_equal(np.asarray(new.dist), np.asarray(old.dist))

@@ -30,9 +30,24 @@ KS = (1, 5, 32)
 R = 4    # DTW band
 
 
-def _bitwise(got, want, stats=True):
+def _bitwise(got, want, stats=True, expanded=True):
+    """ids (and stats) bit for bit; distances too, except those of the
+    expanded form (ED, cosine: ``expanded=True``) where the Pallas kernels
+    run (interpret/pallas modes): the fused ED kernel sums its dots in its
+    own order, so those are held to the expanded form's worst-case f32
+    bound instead (DESIGN.md §8).  DTW distances stay bit for bit."""
+    from repro.kernels import ops
     assert np.array_equal(np.asarray(got.idx), np.asarray(want.idx))
-    assert np.array_equal(np.asarray(got.dist), np.asarray(want.dist))
+    if not (expanded and ops._use_pallas()[0]):
+        assert np.array_equal(np.asarray(got.dist), np.asarray(want.dist))
+    else:
+        n = 128                     # the longest fixture series
+        u = 2.0 ** -24
+        gamma = n * u / (1 - n * u)
+        g2 = np.asarray(got.dist, np.float64) ** 2
+        w2 = np.asarray(want.dist, np.float64) ** 2
+        assert np.all(np.abs(g2 - w2)
+                      <= (4 * gamma + 8 * u) * 2 * n + 4 * u * w2)
     if stats:
         for g, w in zip(got.stats, want.stats):
             assert np.array_equal(np.asarray(g), np.asarray(w))
@@ -130,7 +145,7 @@ def test_parity_dtw_query_major(data, k):
     raw, qs = data
     idx = core.build(jnp.asarray(raw[:512]), capacity=64)
     _bitwise(D.search_dtw(idx, qs, r=R, k=k),
-             legacy.search_dtw(idx, qs, r=R, k=k))
+             legacy.search_dtw(idx, qs, r=R, k=k), expanded=False)
 
 
 @pytest.mark.parametrize("k", KS)
@@ -155,7 +170,7 @@ def test_parity_padding_k_gt_n_real(tiny, k):
     _bitwise(search_paris(tidx, qs, k=k, chunk=8),
              legacy.search_paris(tidx, qs, k=k, chunk=8))
     _bitwise(D.search_dtw(tidx, qs, r=R, k=k),
-             legacy.search_dtw(tidx, qs, r=R, k=k))
+             legacy.search_dtw(tidx, qs, r=R, k=k), expanded=False)
     if k > 20:
         got = core.search(tidx, qs, k=k)
         assert np.all(np.asarray(got.idx)[:, 20:] == -1)

@@ -86,13 +86,12 @@ def test_collective_bytes_parsed():
     run_subprocess("""
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch import hlo_analysis as H
 mesh = jax.make_mesh((8,), ("d",))
 
 def f(x):
-    return shard_map(lambda a: jax.lax.psum(a, "d"), mesh=mesh,
-                     in_specs=P("d"), out_specs=P())(x)
+    return jax.shard_map(lambda a: jax.lax.psum(a, "d"), mesh=mesh,
+                         in_specs=P("d"), out_specs=P())(x)
 x = jax.ShapeDtypeStruct((8, 1024), jnp.float32)
 comp = jax.jit(f).lower(x).compile()
 t = H.analyze_text(comp.as_text())
