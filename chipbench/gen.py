@@ -1,0 +1,78 @@
+"""The run's data, made on the device from ``--seed``.
+
+Every array comes from a ``jax.random`` key derived from the seed, so
+the same seed gives the same collection and the same queries on every
+run, and another seed another collection and other queries.  The
+generators are found by name: the configuration's ``dataset`` in
+``datasets/<name>.py`` (``chunk(key, i, rows=, length=)``), the
+traffic's ``queries`` in ``queries/<name>.py`` (``make(cfg, traffic,
+key, count, rows_of)``).
+
+A collection is made in chunks of ``CHUNK`` series by one compiled
+program: the in-memory path places the chunks into one device buffer,
+the on-disk path writes them to its series file, and the reference
+remakes the same chunks after the window, bit for bit.  No chunk is
+larger than 128 MiB, so generating a collection adds little to the
+device's peak memory beyond the collection itself.
+"""
+from __future__ import annotations
+
+import functools
+from typing import Callable, Iterator
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+import parts
+
+CHUNK = 1 << 17
+# sub-streams of the seed's key
+_COLLECTION, _QUERIES, _WARMUP = range(3)
+
+
+def key(seed: int, stream: int) -> jax.Array:
+    """The key of ``stream`` under ``seed``, any whole number below
+    2**64 (both 32-bit halves count)."""
+    base = jax.random.key(seed & 0xFFFFFFFF)
+    return jax.random.fold_in(jax.random.fold_in(base, seed >> 32), stream)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _place(buf: jax.Array, part: jax.Array, start: jax.Array) -> jax.Array:
+    return jax.lax.dynamic_update_slice_in_dim(buf, part, start, axis=0)
+
+
+def collection_chunks(cfg: dict, seed: int) -> Iterator[jax.Array]:
+    """The configuration's collection, chunk by chunk, on the device."""
+    dataset = parts.load("datasets", cfg["dataset"])
+    n_series, length = cfg["n_series"], cfg["length"]
+    rows = min(CHUNK, n_series)
+    if n_series % rows:
+        raise ValueError(f"{n_series} series do not cut into chunks of "
+                         f"{rows}")
+    k = key(seed, _COLLECTION)
+    for i in range(n_series // rows):
+        yield dataset.chunk(k, jnp.int32(i), rows=rows, length=length)
+
+
+def collection(cfg: dict, seed: int) -> jax.Array:
+    """The whole collection (N, n) float32 on the device."""
+    buf = jnp.zeros((cfg["n_series"], cfg["length"]), jnp.float32)
+    start = 0
+    for part in collection_chunks(cfg, seed):
+        buf = _place(buf, part, jnp.int32(start))
+        start += part.shape[0]
+    return buf
+
+
+def queries(cfg: dict, traffic: dict, seed: int, count: int,
+            rows_of: Callable[[np.ndarray], jax.Array] | None = None, *,
+            warmup: bool = False) -> np.ndarray:
+    """(count, length) float32 queries on the host, where a client holds
+    them.  ``rows_of(ids)`` returns collection rows, for generators that
+    draw members.  ``warmup`` draws from a stream of its own, so warming
+    up never sends a query of the window."""
+    kind = parts.load("queries", traffic["queries"])
+    k = key(seed, _WARMUP if warmup else _QUERIES)
+    return np.asarray(kind.make(cfg, traffic, k, count, rows_of))
