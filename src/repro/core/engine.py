@@ -54,6 +54,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import frontier as frontier_lib
 from repro.core import isax
@@ -871,22 +872,23 @@ class _GroupDispatcher:
     def __call__(self, qs, front, stats, gids: list[int]):
         index, needs = self.index, self.needs
         self.dispatches += 1
-        if len(gids) == 1:
-            b = gids[0]
-            lo = index.slo[b] if needs else None
-            hi = index.shi[b] if needs else None
-            return _cached_refine_step(
-                self.metric, qs, front, stats, self.fetch(b), index.ids[b],
-                lo, hi, self.block_lb[:, b], self.thr0,
-                n=index.n, w=index.w)
-        blocks = jnp.stack([self.fetch(b) for b in gids])        # (G, C, n)
-        gi = jnp.asarray(np.asarray(gids, dtype=np.int32))       # host ids
-        lo_g = index.slo[gi] if needs else None                  # (G, w, C)
-        hi_g = index.shi[gi] if needs else None
-        return _cached_refine_group(
-            self.metric, qs, front, stats, blocks, index.ids[gi],
-            lo_g, hi_g, jnp.transpose(self.block_lb[:, gi]),     # (G, Q)
-            self.thr0, n=index.n, w=index.w)
+        with TraceAnnotation("walk.dispatch"):
+            if len(gids) == 1:
+                b = gids[0]
+                lo = index.slo[b] if needs else None
+                hi = index.shi[b] if needs else None
+                return _cached_refine_step(
+                    self.metric, qs, front, stats, self.fetch(b),
+                    index.ids[b], lo, hi, self.block_lb[:, b], self.thr0,
+                    n=index.n, w=index.w)
+            blocks = jnp.stack([self.fetch(b) for b in gids])    # (G, C, n)
+            gi = jnp.asarray(np.asarray(gids, dtype=np.int32))   # host ids
+            lo_g = index.slo[gi] if needs else None              # (G, w, C)
+            hi_g = index.shi[gi] if needs else None
+            return _cached_refine_group(
+                self.metric, qs, front, stats, blocks, index.ids[gi],
+                lo_g, hi_g, jnp.transpose(self.block_lb[:, gi]),  # (G, Q)
+                self.thr0, n=index.n, w=index.w)
 
 
 def _cached_stage_a(index, plan, prep: PreparedSearch, block_lb_h,
@@ -904,15 +906,16 @@ def _cached_stage_a(index, plan, prep: PreparedSearch, block_lb_h,
                                 initial_threshold)
     stage_a = [int(b) for b in np.unique(np.argmin(block_lb_h, axis=1))]
     i = 0
-    while i < len(stage_a):
-        gids = stage_a[i:i + group_blocks]
-        for b in gids:                     # group reads first, in order
-            speculate(b)
-        nxt = i + len(gids)
-        for b in stage_a[nxt:nxt + pipeline_depth]:    # depth-D lookahead
-            speculate(b)
-        front, stats = dispatch(qs, front, stats, gids)
-        i = nxt
+    with TraceAnnotation("walk.stage_a"):
+        while i < len(stage_a):
+            gids = stage_a[i:i + group_blocks]
+            for b in gids:                     # group reads first, in order
+                speculate(b)
+            nxt = i + len(gids)
+            for b in stage_a[nxt:nxt + pipeline_depth]:  # depth-D lookahead
+                speculate(b)
+            front, stats = dispatch(qs, front, stats, gids)
+            i = nxt
     if telemetry is not None:
         telemetry["stage_a_blocks"] = len(stage_a)
         telemetry["stage_a_dispatches"] = dispatch.dispatches
@@ -959,6 +962,14 @@ def run_cached(index: BlockIndex, queries: jax.Array, plan: QueryPlan, *,
     ``walk_blocks`` — so callers can verify the amortization
     (syncs ~= refined_blocks / G + 1).
 
+    The host walk records profiler spans (``jax.profiler
+    .TraceAnnotation``; each costs about a microsecond with the profiler
+    off): ``walk.prep`` (query prep, block ranking and its landing on
+    the host), ``walk.stage_a``, ``walk.schedule``, ``walk.scan`` (each
+    survivor scan), ``walk.dispatch`` (each group's slices, fetch and
+    enqueue) and ``walk.sync`` (each threshold pull, as many as
+    ``syncs``).
+
     Returns ``(frontier, stats, state)``: the local frontier, the
     finalized work stats, and the walk's end state as a resumable
     ``PreparedSearch`` (pre-finalize stats; ``refined`` holds every
@@ -987,9 +998,10 @@ def run_cached(index: BlockIndex, queries: jax.Array, plan: QueryPlan, *,
     _check_pipeline_knobs(pipeline_depth, group_blocks)
     n_blocks = index.n_blocks
     if prepared is None:
-        prep = cached_setup(index, queries, plan)
-        prep = _cached_stage_a(index, plan, prep,
-                               np.asarray(prep.block_lb),  # sync: 1/batch
+        with TraceAnnotation("walk.prep"):
+            prep = cached_setup(index, queries, plan)
+            lb_h = np.asarray(prep.block_lb)                 # sync: 1/batch
+        prep = _cached_stage_a(index, plan, prep, lb_h,
                                fetch, speculate, initial_threshold,
                                pipeline_depth=pipeline_depth,
                                group_blocks=group_blocks,
@@ -1000,24 +1012,26 @@ def run_cached(index: BlockIndex, queries: jax.Array, plan: QueryPlan, *,
     qs, front, block_lb, stats = (prep.qs, prep.front, prep.block_lb,
                                   prep.stats)
     done = prep.refined
-    # one sync per batch: the host copy drives block ordering and the
-    # suffix-min stop table; the walk itself then syncs once per GROUP
-    # (the '# sync' sites below), which is the PR-9 amortization claim
-    block_lb_h = np.asarray(block_lb)                            # sync
-    dispatch = _GroupDispatcher(index, plan, block_lb, fetch,
-                                initial_threshold)
     budget = plan.deadline_blocks        # refines left; None = unbounded
-
-    # -- block-major walk over the surviving schedule -----------------
-    order, sched_lb, _ = block_major_schedule(block_lb_h, xp=np)
-    # slot_done[s]: schedule slot s already refined (stage A / a resumed
-    # run) or consumed by this walk — the survivor scan masks it out
-    slot_done = (np.isin(order, np.fromiter(done, np.int64, len(done)))
-                 if done else np.zeros(n_blocks, dtype=bool))
+    with TraceAnnotation("walk.schedule"):
+        # one sync per batch: the host copy drives block ordering and the
+        # suffix-min stop table; the walk itself then syncs once per GROUP
+        # (the '# sync' sites below), which is the pipeline's amortization
+        block_lb_h = np.asarray(block_lb)                        # sync
+        dispatch = _GroupDispatcher(index, plan, block_lb, fetch,
+                                    initial_threshold)
+        # -- block-major walk over the surviving schedule -------------
+        order, sched_lb, _ = block_major_schedule(block_lb_h, xp=np)
+        # slot_done[s]: schedule slot s already refined (stage A / a
+        # resumed run) or consumed by this walk — the survivor scan
+        # masks it out
+        slot_done = (np.isin(order, np.fromiter(done, np.int64, len(done)))
+                     if done else np.zeros(n_blocks, dtype=bool))
 
     walked: list[int] = []               # blocks THIS walk refined
     n_syncs = 1
-    thr_h = np.asarray(_bound(front, initial_threshold))              # sync
+    with TraceAnnotation("walk.sync"):
+        thr_h = np.asarray(_bound(front, initial_threshold))          # sync
     ptr = 0
     while ptr < n_blocks:
         if budget is not None and len(walked) >= budget:
@@ -1027,8 +1041,9 @@ def run_cached(index: BlockIndex, queries: jax.Array, plan: QueryPlan, *,
         # if unconsumed and any query's scheduled LB beats the bound.
         # (No survivors <=> the suffix-min stopping rule fires: suffix
         # minima over pruned slots cannot beat thr either.)
-        live = np.flatnonzero(~slot_done[ptr:] & np.any(
-            sched_lb[:, ptr:] < thr_h[:, None], axis=0)) + ptr
+        with TraceAnnotation("walk.scan"):
+            live = np.flatnonzero(~slot_done[ptr:] & np.any(
+                sched_lb[:, ptr:] < thr_h[:, None], axis=0)) + ptr
         if live.size == 0:
             break                       # nothing later helps any query
         g = (group_blocks if budget is None
@@ -1050,16 +1065,15 @@ def run_cached(index: BlockIndex, queries: jax.Array, plan: QueryPlan, *,
         # deadline-cut walk leaves it warm for its own continuation).
         for s in live[g:g + pipeline_depth]:
             speculate(int(order[s]))
-        thr_h = np.asarray(_bound(front, initial_threshold))  # 1 sync/group
+        with TraceAnnotation("walk.sync"):
+            thr_h = np.asarray(_bound(front, initial_threshold))  # sync
         n_syncs += 1
         # slots in [ptr, take[-1]] not taken were pruned under a bound
         # that only tightened since — jump straight past the group
         ptr = int(take[-1]) + 1
     if telemetry is not None:
         telemetry.update(syncs=n_syncs, dispatches=dispatch.dispatches,
-                         walk_blocks=len(walked),
-                         pipeline_depth=pipeline_depth,
-                         group_blocks=group_blocks)
+                         walk_blocks=len(walked))
     state = dataclasses.replace(prep, front=front, stats=stats,
                                 refined=done | frozenset(walked))
     return front, plan.metric.finalize_stats(stats, index.capacity), state
@@ -1079,9 +1093,10 @@ def run_cached_stage_a(index: BlockIndex, queries: jax.Array,
     ``pipeline_depth``/``group_blocks`` pipeline the stage-A chain the
     same way they pipeline the walk (see ``run_cached``)."""
     _check_pipeline_knobs(pipeline_depth, group_blocks)
-    prep = cached_setup(index, queries, plan)
-    return _cached_stage_a(index, plan, prep,
-                           np.asarray(prep.block_lb),  # sync: 1/round
+    with TraceAnnotation("walk.prep"):
+        prep = cached_setup(index, queries, plan)
+        lb_h = np.asarray(prep.block_lb)                     # sync: 1/round
+    return _cached_stage_a(index, plan, prep, lb_h,
                            fetch, speculate, None,
                            pipeline_depth=pipeline_depth,
                            group_blocks=group_blocks)

@@ -28,6 +28,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core import engine
 from repro.core.engine import ED, QueryPlan
@@ -77,13 +78,16 @@ def search(index: BlockIndex, queries: jax.Array, *, k: int = 1,
     anytime answers; None = exact).
     ``normalize_queries=False`` is the generic-vector path (core/vector.py):
     the index was built with normalize=False and queries arrive prepared.
+    The call to ``engine.run`` (the host's enqueue of the program) is the
+    profiler span ``engine.dispatch``.
     """
     plan = QueryPlan(metric=ED(normalize=normalize_queries,
                                lb_filter=lb_filter),
                      schedule="query_major", k=k,
                      blocks_per_iter=blocks_per_iter,
                      deadline_blocks=deadline_blocks)
-    return engine.run(index, queries, plan, initial_threshold)
+    with TraceAnnotation("engine.dispatch"):
+        return engine.run(index, queries, plan, initial_threshold)
 
 
 def search_block_major(index: BlockIndex, queries: jax.Array, *, k: int = 1,

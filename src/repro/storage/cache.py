@@ -59,6 +59,7 @@ from concurrent.futures import Future, ThreadPoolExecutor
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis import sanitize
 from repro.core import engine
@@ -94,6 +95,12 @@ class BlockCache:
     their block neither resident nor in flight — the walk stalls the
     pipeline was supposed to hide (``bench_serve.py`` reports the
     fraction as reader-pool effectiveness).
+
+    Profiler spans, each tagged ``block=<id>``: ``cache.read_file`` and
+    ``cache.upload`` on the reader threads (the host copy out of the
+    file, the ``device_put``), ``cache.wait`` on the caller's thread
+    where ``get`` blocks on a read in flight or a demand miss (a hit
+    records none).
     """
 
     def __init__(self, host: HostRawBlocks, capacity_blocks: int, *,
@@ -139,7 +146,10 @@ class BlockCache:
     def _read(self, block_id: int) -> jax.Array:
         """Reader-thread body: disk -> host copy -> device, then publish."""
         try:
-            dev = jax.device_put(self.host.fetch(block_id))
+            with TraceAnnotation("cache.read_file", block=block_id):
+                raw = self.host.fetch(block_id)
+            with TraceAnnotation("cache.upload", block=block_id):
+                dev = jax.device_put(raw)
         except BaseException:
             # a failed read must not poison the cache: drop the in-flight
             # entry so the block no longer looks present and the next
@@ -192,7 +202,8 @@ class BlockCache:
                 self.demand_misses += 1
                 fut = self._reader.submit(self._read, block_id)
                 self._inflight[block_id] = fut
-        return fut.result()
+        with TraceAnnotation("cache.wait", block=block_id):
+            return fut.result()
 
     def drain(self) -> None:
         """Wait for every in-flight read to land (settles the counters).
@@ -532,7 +543,8 @@ class SearchSession:
         Answers are bit-identical for every setting — the knobs trade
         speculative I/O for latency, never exactness (see
         ``engine.run_cached``).  The walk's host-side counters land in
-        ``session.last_telemetry``.
+        ``session.last_telemetry``; the drain, the bill and the result's
+        distances after the walk are the profiler span ``walk.settle``.
         """
         index = self.index
         plan = self._plan(k, lb_filter, normalize_queries, metric)
@@ -573,11 +585,12 @@ class SearchSession:
             pipeline_depth=d, group_blocks=g, telemetry=tel)
         self.last_telemetry = tel
 
-        self.cache.drain()  # settle the last speculation into this bill
-        io = self._bill(tracker, carry_blocks=carry_blocks,
-                        carry_bytes=carry_bytes,
-                        blocks_refined=len(state.refined))
-        dist = frontier_lib.result_dists(front)
+        with TraceAnnotation("walk.settle"):
+            self.cache.drain()  # settle the last speculation into this bill
+            io = self._bill(tracker, carry_blocks=carry_blocks,
+                            carry_bytes=carry_bytes,
+                            blocks_refined=len(state.refined))
+            dist = frontier_lib.result_dists(front)
         if deadline_blocks is None:
             return OocSearchResult(dist=dist, idx=front.ids,
                                    stats=stats, io=io)
