@@ -32,10 +32,11 @@ a resumable ``PreparedSearch`` (``prepare`` / ``run_cached_stage_a``)
 that round 2 (``run`` / ``run_cached``) resumes instead of recomputing.  Every ``Metric.distances``
 call lives in this module: the two pruned refine loops
 (``panel_refine``, shared by both block-major backends, and the
-gathered refine inside ``_query_major``) are where the DESIGN.md §8
-fused LB+select kernel plugs in; stage-A seeding (``prepare`` /
-``_cached_stage_a``) and the flat chunk refine (``run_flat``) also
-call it and need the same swap to fuse end to end.
+query-major refine inside ``_query_major``) ask the metric for their
+whole LB + distance + select step (``panel_topk``, ``gathered_topk``),
+which ED runs as one DESIGN.md §8 fused kernel each; stage-A seeding
+(``prepare`` / ``_cached_stage_a``) and the flat chunk refine
+(``run_flat``) also call it and need the same swap to fuse end to end.
 
 Exactness: a schedule only skips work whose metric lower bound is >= the
 frontier's k-th-best distance, and every metric's bounds satisfy
@@ -178,17 +179,6 @@ class ED:
         """
         return ops.lb_scan_planar(qs.aux[0], lo, hi, n=n)
 
-    def series_lb(self, qs: QueryState, block: jax.Array, lo: jax.Array,
-                  hi: jax.Array, *, n: int, w: int) -> jax.Array:
-        q_paa = qs.aux[0]
-        if lo.ndim == 2:                                   # panel (w, C)
-            qe = q_paa[:, :, None]                         # (Q, w, 1)
-            dd = jnp.maximum(jnp.maximum(lo[None] - qe, qe - hi[None]), 0.0)
-            return (n / w) * jnp.sum(dd * dd, axis=1)      # (Q, C)
-        qe = q_paa[:, None, :, None]                       # gathered (Q,1,w,1)
-        dd = jnp.maximum(jnp.maximum(lo - qe, qe - hi), 0.0)
-        return (n / w) * jnp.sum(dd * dd, axis=2)          # (Q, K, C)
-
     def distances(self, qs: QueryState, block: jax.Array) -> jax.Array:
         if block.ndim == 2:            # shared (C, n) panel: one MXU pass
             return ops.batch_l2(qs.q, block)
@@ -211,6 +201,23 @@ class ED:
         d = jnp.where(live, self.distances(qs, block), INF)
         sd, si = ops.block_topk(d, jnp.where(live, ids_b[None, :], -1), k)
         return sd, si, jnp.sum(live, axis=1, dtype=jnp.int32)
+
+    def gathered_topk(self, qs: QueryState, index: BlockIndex,
+                      idxs: jax.Array, active: jax.Array, thr: jax.Array,
+                      front: Frontier
+                      ) -> tuple[jax.Array, jax.Array, jax.Array]:
+        """The query-major refine: each query against its own (Q, K)
+        blocks ``idxs`` -> (sel_d (Q, k), sel_id (Q, k), n_live (Q,)),
+        the candidates for ``front``.
+
+        With the MINDIST filter on, ONE kernel reads each active block
+        in place (``ops.gathered_panel_topk``), selecting only within
+        the frontier's k-th; without it, the gather."""
+        if self.lb_filter:
+            return ops.gathered_panel_topk(
+                qs.q, qs.aux[0], index.raw, index.slo, index.shi, index.ids,
+                idxs, active, thr, front.threshold(), k=front.k, n=index.n)
+        return gathered_refine(self, qs, index, idxs, active, thr, front.k)
 
     def finalize_stats(self, stats: SearchStats, capacity: int
                        ) -> SearchStats:
@@ -289,6 +296,14 @@ class DTW:
         d = jnp.where(live, self.distances(qs, block), INF)
         sd, si = ops.block_topk(d, jnp.where(live, ids_b[None, :], -1), k)
         return sd, si, jnp.sum(live, axis=1, dtype=jnp.int32)
+
+    def gathered_topk(self, qs: QueryState, index: BlockIndex,
+                      idxs: jax.Array, active: jax.Array, thr: jax.Array,
+                      front: Frontier
+                      ) -> tuple[jax.Array, jax.Array, jax.Array]:
+        """The query-major refine by gathering: LB_Keogh and the banded
+        DP both read the raw values (``ops.dtw_panel``)."""
+        return gathered_refine(self, qs, index, idxs, active, thr, front.k)
 
     def finalize_stats(self, stats: SearchStats, capacity: int
                        ) -> SearchStats:
@@ -474,6 +489,30 @@ def panel_refine(metric, qs: QueryState, front: Frontier, stats: SearchStats,
 # device backend: the two ordered schedules + the flat scan
 # ---------------------------------------------------------------------------
 
+def gathered_refine(metric, qs: QueryState, index: BlockIndex,
+                    idxs: jax.Array, active: jax.Array, thr: jax.Array,
+                    k: int) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Query-major refine by gathering each query's K blocks into a
+    (Q, K, C, n) copy: the path of metrics whose filter reads the raw
+    values (DTW) or that do not filter (ED without its LB filter)."""
+    qn = qs.q.shape[0]
+    blocks = index.raw[idxs]                                    # (Q,K,C,n)
+    ids = index.ids[idxs]                                       # (Q,K,C)
+    if metric.filters:
+        s_lb = metric.series_lb(qs, blocks, None, None,
+                                n=index.n, w=index.w)           # (Q,K,C)
+        s_act = (s_lb < thr[:, None, None]) & active[..., None]
+    else:
+        s_act = jnp.broadcast_to(active[..., None], ids.shape)
+    d = metric.distances(qs, blocks)                            # (Q,K,C)
+    live = s_act & (ids >= 0)
+    # blocks partition the series and idxs rows are distinct, so ids are
+    # unique per row: block_topk's subset-exactness holds
+    sd, si = ops.block_topk(jnp.where(live, d, INF).reshape(qn, -1),
+                            jnp.where(live, ids, -1).reshape(qn, -1), k)
+    return sd, si, jnp.sum(live, axis=(1, 2), dtype=jnp.int32)
+
+
 def _query_major(metric, index: BlockIndex, qs: QueryState, front: Frontier,
                  block_lb: jax.Array, stats0: SearchStats, *,
                  blocks_per_iter: int, deadline_blocks: int | None,
@@ -484,10 +523,11 @@ def _query_major(metric, index: BlockIndex, qs: QueryState, front: Frontier,
     ``blocks_per_iter`` blocks per trip; exits when every query's next
     block LB >= its pruning bound.  Ordered traversal + that stopping
     rule ARE the paper's priority-queue semantics; the heap itself is an
-    artifact of MIMD threads.
+    artifact of MIMD threads.  Each trip's refine is the metric's
+    ``gathered_topk``: for ED one kernel that reads every active block
+    in place (``ops.gathered_panel_topk``), else ``gathered_refine``.
     """
-    b, c, n = index.raw.shape
-    qn = qs.q.shape[0]
+    b, c, _ = index.raw.shape
     kb = min(blocks_per_iter, b)
 
     order = jnp.argsort(block_lb, axis=1)                     # (Q, B)
@@ -519,32 +559,15 @@ def _query_major(metric, index: BlockIndex, qs: QueryState, front: Frontier,
 
         def refine(carry):
             f_i, st_i = carry
-            blocks = index.raw[idxs]                                # (Q,K,C,n)
-            ids = index.ids[idxs]                                   # (Q,K,C)
-            if metric.filters:
-                lo = index.slo[idxs] if metric.needs_bounds else None
-                hi = index.shi[idxs] if metric.needs_bounds else None
-                s_lb = metric.series_lb(qs, blocks, lo, hi,
-                                        n=n, w=index.w)             # (Q,K,C)
-                s_act = (s_lb < thr[:, None, None]) & active[..., None]
-            else:
-                s_act = jnp.broadcast_to(active[..., None], ids.shape)
-            d = metric.distances(qs, blocks)                        # (Q,K,C)
-            live = s_act & (ids >= 0)
-            # blocks partition the series and idxs rows are distinct, so
-            # ids are unique per row: block_topk's subset-exactness holds
-            sd, si = ops.block_topk(
-                jnp.where(live, d, INF).reshape(qn, -1),
-                jnp.where(live, ids, -1).reshape(qn, -1), f_i.k)
+            sd, si, nlive = metric.gathered_topk(qs, index, idxs, active,
+                                                 thr, f_i)
             f_n = f_i.insert_topk(sd, si)
+            nact = jnp.sum(active, axis=1, dtype=jnp.int32)
             st_n = SearchStats(
-                blocks_visited=st_i.blocks_visited
-                + jnp.sum(active, axis=1, dtype=jnp.int32),
-                series_refined=st_i.series_refined
-                + jnp.sum(live, axis=(1, 2), dtype=jnp.int32),
+                blocks_visited=st_i.blocks_visited + nact,
+                series_refined=st_i.series_refined + nlive,
                 lb_series=st_i.lb_series
-                + (jnp.sum(active, axis=1, dtype=jnp.int32) * c
-                   if metric.filters else 0),
+                + (nact * c if metric.filters else 0),
                 iters=st_i.iters,
             )
             return f_n, st_n

@@ -57,6 +57,7 @@ from repro.kernels.block_topk import block_topk as _block_topk_kernel
 from repro.kernels.dtw_band import dtw_band_panel as _dtw_band_kernel
 from repro.kernels.fused_refine import (
     fused_panel_topk as _fused_refine_kernel,
+    gathered_panel_topk as _gathered_refine_kernel,
 )
 from repro.kernels.isax_summarize import isax_summarize as _summ_kernel
 from repro.kernels.lb_scan import lb_scan as _lb_kernel
@@ -174,6 +175,31 @@ def fused_panel_topk(q: jax.Array, q_paa: jax.Array, block: jax.Array,
                                     k=k, n=n, interpret=interp)
     return ref.fused_panel_topk_ref(q, q_paa, block, lo, hi, ids, thr,
                                     k=k, n=n)
+
+
+def gathered_panel_topk(q: jax.Array, q_paa: jax.Array, raw: jax.Array,
+                        slo: jax.Array, shi: jax.Array, ids: jax.Array,
+                        idxs: jax.Array, active: jax.Array, thr: jax.Array,
+                        bound: jax.Array, *, k: int, n: int
+                        ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Fused LB + distance + select of each query against its own K blocks
+    of the index, read in place (the query-major refine).
+
+    q (Q, n), q_paa (Q, w); raw (B, C, n), slo/shi (B, w, C), ids (B, C);
+    idxs (Q, K) block ids, active (Q, K) bool, thr (Q,) pruning bound,
+    bound (Q,) the largest distance selected
+    -> (sel_d (Q, k), sel_id (Q, k), n_live (Q,) int32).
+    The kernel copies whole blocks from HBM, so it takes lane-aligned
+    blocks (C and n multiples of 128) and leaves others to the oracle.
+    """
+    use, interp = _use_pallas()
+    _, c, n_len = raw.shape
+    if use and k <= c and c % 128 == 0 and n_len % 128 == 0:
+        return _gathered_refine_kernel(q, q_paa, raw, slo, shi, ids, idxs,
+                                       active, thr, bound, k=k, n=n,
+                                       interpret=interp)
+    return ref.gathered_panel_topk_ref(q, q_paa, raw, slo, shi, ids, idxs,
+                                       active, thr, bound, k=k, n=n)
 
 
 def dtw_panel(q: jax.Array, x: jax.Array, *, r: int) -> jax.Array:

@@ -132,6 +132,45 @@ def fused_panel_topk_ref(q: jax.Array, q_paa: jax.Array, block: jax.Array,
     return sd, si, jnp.sum(live, axis=1, dtype=jnp.int32)
 
 
+def gathered_panel_topk_ref(q: jax.Array, q_paa: jax.Array, raw: jax.Array,
+                            slo: jax.Array, shi: jax.Array, ids: jax.Array,
+                            idxs: jax.Array, active: jax.Array,
+                            thr: jax.Array, bound: jax.Array, *, k: int,
+                            n: int
+                            ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Oracle for kernels/fused_refine.py's gathered entry: the query-major
+    refine the engine ran before fusion, which gathers each query's K
+    blocks into a (Q, K, C, n) copy.
+
+    q (Q, n), q_paa (Q, w); raw (B, C, n), slo/shi (B, w, C), ids (B, C)
+    the whole index; idxs (Q, K) block ids, active (Q, K) bool, thr (Q,)
+    pruning bound, bound (Q,) the largest distance selected.  Returns the
+    (dist, id)-lex top-k of each query's live lanes within ``bound`` over
+    its K blocks — the rest are (INF, -1) — and the live count.  With
+    ``bound`` the frontier's k-th, inserting the result leaves the same
+    frontier as inserting every live lane: no lane above the k-th can
+    enter it or lower a held one.  The distance is
+    ``core.frontier.query_block_l2``'s expanded form, repeated here
+    because ``frontier`` imports this module.
+    """
+    qn, w = q_paa.shape
+    blocks = raw[idxs]                                        # (Q, K, C, n)
+    idg = ids[idxs]                                           # (Q, K, C)
+    qe = q_paa[:, None, :, None]                              # (Q, 1, w, 1)
+    dd = jnp.maximum(jnp.maximum(slo[idxs] - qe, qe - shi[idxs]), 0.0)
+    s_lb = (n / w) * jnp.sum(dd * dd, axis=2)                 # (Q, K, C)
+    live = (s_lb < thr[:, None, None]) & active[..., None] & (idg >= 0)
+    qq = jnp.sum(q * q, axis=-1)[:, None, None]               # (Q, 1, 1)
+    xx = jnp.sum(blocks * blocks, axis=-1)                    # (Q, K, C)
+    cross = jnp.einsum("qn,q...n->q...", q, blocks,
+                       precision=jax.lax.Precision.HIGHEST)
+    d = jnp.maximum(qq + xx - 2.0 * cross, 0.0)
+    keep = live & (d <= bound[:, None, None])
+    sd, si = topk_by_dist_id(jnp.where(keep, d, INF).reshape(qn, -1),
+                             jnp.where(keep, idg, -1).reshape(qn, -1), k)
+    return sd, si, jnp.sum(live, axis=(1, 2), dtype=jnp.int32)
+
+
 def dtw_band_ref(a: jax.Array, b: jax.Array, r: int) -> jax.Array:
     """Exact squared-DTW with band r. a (..., n) vs b (..., n), broadcast.
 

@@ -187,6 +187,74 @@ def test_parity_ed_cached_backend(data, opened, k):
 
 
 # ---------------------------------------------------------------------------
+# query-major refine: the gathered kernel (interpret) against its oracle
+# (ref) through engine.run, Q = 16, K = blocks_per_iter = 4, k = 10
+# ---------------------------------------------------------------------------
+
+def _query_major_case(metric, variant):
+    """1,500 series of length 128 in 12 blocks of 128 (the kernel takes
+    lane-aligned blocks), the last one part padding."""
+    rng = np.random.default_rng(41)
+    if isinstance(metric, engine.Cosine):
+        raw = rng.standard_normal((1500, 128)).astype(np.float32)
+        index = vector.build_vector_index(jnp.asarray(raw), capacity=128)
+        qs = jnp.asarray(raw[rng.choice(1500, 16, replace=False)] + 0.05)
+    else:
+        raw = random_walk(1500, 128, seed=43)
+        index = core.build(jnp.asarray(raw), capacity=128)
+        qs = jnp.asarray(raw[rng.choice(1500, 16, replace=False)]
+                         + 0.1 * rng.standard_normal((16, 128))
+                         .astype(np.float32))
+    kw = {}
+    if variant == "deadline":
+        kw["deadline_blocks"] = 6
+    plan = engine.QueryPlan(metric=metric, schedule="query_major", k=10,
+                            blocks_per_iter=4, **kw)
+    thr = None
+    if variant == "threshold":
+        exact = engine.run(index, qs, engine.QueryPlan(
+            metric=metric, schedule="query_major", k=1))
+        thr = jnp.asarray(exact.dist[:, 0]) ** 2 * 1.5 + 1e-3
+    return index, qs, plan, thr
+
+
+@pytest.mark.parametrize("variant", ["exact", "deadline", "threshold"])
+@pytest.mark.parametrize("metric", [engine.ED(), engine.Cosine()],
+                         ids=["ed", "cosine"])
+def test_query_major_kernel_vs_oracle(metric, variant):
+    """Ids, blocks visited, series refined and trips identical; distances
+    within the expanded form's f32 bound."""
+    from repro.kernels import ops
+    index, qs, plan, thr = _query_major_case(metric, variant)
+    with ops.kernel_mode("ref"):
+        want = engine.run(index, qs, plan, thr)
+    with ops.kernel_mode("interpret"):
+        got = engine.run(index, qs, plan, thr)
+    assert np.array_equal(np.asarray(got.idx), np.asarray(want.idx))
+    for g, w in zip(got.stats, want.stats):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    n = index.n                     # ||q||^2 = ||x||^2 = n for both
+    u = 2.0 ** -24
+    gamma = n * u / (1 - n * u)
+    g2 = np.asarray(got.dist, np.float64) ** 2
+    w2 = np.asarray(want.dist, np.float64) ** 2
+    assert np.all(np.abs(g2 - w2) <= (4 * gamma + 8 * u) * 2 * n + 4 * u * w2)
+    assert int(want.stats.iters) > 1
+
+
+def test_query_major_dtw_unchanged_by_mode(data):
+    """DTW keeps the gathered path: bit for bit across modes."""
+    from repro.kernels import ops
+    raw, qs = data
+    index = core.build(jnp.asarray(raw[:512]), capacity=64)
+    with ops.kernel_mode("ref"):
+        want = D.search_dtw(index, qs, r=R, k=10, blocks_per_iter=4)
+    with ops.kernel_mode("interpret"):
+        got = D.search_dtw(index, qs, r=R, k=10, blocks_per_iter=4)
+    _bitwise(got, want, expanded=False)
+
+
+# ---------------------------------------------------------------------------
 # new cells: exactness against their oracle paths
 # ---------------------------------------------------------------------------
 

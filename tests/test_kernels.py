@@ -402,6 +402,128 @@ def test_fused_refine_padding_lanes_ignored():
 
 
 # ---------------------------------------------------------------------------
+# gathered refine: each query against its own K blocks, read in place
+# ---------------------------------------------------------------------------
+
+def _gathered_inputs(q, kb, k, b=12, c=128, n=128, w=16, thr_val=100.0,
+                     seed=0):
+    """A (b, c, n) index with shuffled ids, q z-normed queries, each with
+    kb distinct blocks of which about 70% are active.  b = 12 puts four
+    blocks past the id table's last whole 8-row tile."""
+    rng = np.random.default_rng(seed)
+    x = isax.znorm(series(b * c, n))
+    _, _, bounds = isax.summarize(x, w=w)                 # (b*c, w, 2)
+    planar = lambda a: a.reshape(b, c, w).transpose(0, 2, 1)
+    qs = isax.znorm(series(q, n))
+    idxs = np.stack([rng.choice(b, kb, replace=False) for _ in range(q)])
+    return dict(
+        q=qs, q_paa=isax.paa(qs, w), raw=x.reshape(b, c, n),
+        slo=planar(bounds[..., 0]), shi=planar(bounds[..., 1]),
+        ids=rng.permutation(b * c).reshape(b, c).astype(np.int32),
+        idxs=idxs.astype(np.int32), active=rng.random((q, kb)) < 0.7,
+        thr=np.full((q,), thr_val, np.float32),
+        bound=np.full((q,), INF, np.float32))
+
+
+def _check_gathered(args, k, n=128):
+    """Ids and live counts exact; distances within the expanded form's
+    worst-case f32 bound (z-normed operands: ||q||^2 = ||x||^2 = n)."""
+    from repro.kernels.fused_refine import gathered_panel_topk
+    args = {a: jnp.asarray(v) for a, v in args.items()}
+    gd, gi, gn = gathered_panel_topk(**args, k=k, n=n, interpret=True)
+    wd, wi, wn = ref.gathered_panel_topk_ref(**args, k=k, n=n)
+    assert np.array_equal(np.asarray(gi), np.asarray(wi))
+    assert np.array_equal(np.asarray(gn), np.asarray(wn))
+    live = np.asarray(wi) >= 0
+    assert np.array_equal(np.asarray(gd)[~live], np.asarray(wd)[~live])
+    np.testing.assert_allclose(np.asarray(gd)[live], np.asarray(wd)[live],
+                               rtol=0, atol=expanded_l2_tol(n, 2 * n))
+    return gd, gi, gn
+
+
+@pytest.mark.parametrize("q", [1, 3, 16])
+@pytest.mark.parametrize("kb", [1, 4])
+@pytest.mark.parametrize("k", [1, 10])
+def test_gathered_refine_sweep(q, kb, k):
+    args = _gathered_inputs(q, kb, k, seed=q * 100 + kb * 10 + k)
+    args["active"][0, 0] = True                 # at least one step runs
+    _check_gathered(args, k)
+
+
+def _edge_inactive_rows(a):
+    a["active"][0] = False
+    a["active"][2] = False
+
+
+def _edge_all_pruned(a):
+    a["thr"][:] = 0.0                           # lb >= 0 prunes every lane
+
+
+def _edge_pad_lanes(a):
+    a["ids"][a["idxs"][0, 0], 80:] = -1         # a block's padded tail
+    a["raw"] = np.asarray(a["raw"]).copy()
+    a["raw"][a["idxs"][0, 0], 80:] = 1.0e4      # RAW_PAD
+    a["active"][0, 0] = True
+    a["thr"][:] = INF
+
+
+def _edge_ties(a):
+    """One block of copies of a series near query 1: its ten lanes in the
+    top-10 tie, and the tie goes to the smaller ids."""
+    b0 = a["idxs"][1, 0]
+    raw = np.asarray(a["raw"]).copy()
+    raw[b0] = np.asarray(a["q"])[1] + 0.01
+    a["raw"] = raw
+    a["active"][1, 0] = True
+    a["thr"][:] = INF
+
+
+def _edge_neg_inf_thr(a):
+    a["active"][1] = True
+    a["thr"][1] = -np.inf
+
+
+def _edge_bound(a):
+    """Only distances within ``bound`` (the frontier's k-th) are selected,
+    here halfway between each row's 5th and 6th; the live count still
+    counts every live lane."""
+    a["thr"][:] = INF
+    a["active"][:] = True
+    sd, _, _ = ref.gathered_panel_topk_ref(
+        **{n: jnp.asarray(v) for n, v in a.items()}, k=10, n=128)
+    a["bound"][:] = (np.asarray(sd)[:, 4] + np.asarray(sd)[:, 5]) / 2
+    a["bound"][3] = 0.0
+
+
+@pytest.mark.parametrize("edge", [_edge_inactive_rows, _edge_all_pruned,
+                                  _edge_pad_lanes, _edge_ties,
+                                  _edge_neg_inf_thr, _edge_bound],
+                         ids=lambda f: f.__name__[6:])
+def test_gathered_refine_edges(edge):
+    args = _gathered_inputs(4, 4, 10, seed=7)
+    edge(args)
+    gd, gi, gn = _check_gathered(args, 10)
+    gi, gn = np.asarray(gi), np.asarray(gn)
+    if edge is _edge_inactive_rows:
+        assert np.all(gi[[0, 2]] == -1) and np.all(gn[[0, 2]] == 0)
+    elif edge is _edge_all_pruned:
+        assert np.all(gi == -1) and np.all(gn == 0)
+        assert np.all(np.asarray(gd) == INF)
+    elif edge is _edge_pad_lanes:
+        pad = set(args["ids"][args["idxs"][0, 0], 80:].tolist())
+        assert not pad & set(gi[0].tolist())
+    elif edge is _edge_ties:
+        block_ids = np.sort(args["ids"][args["idxs"][1, 0]])
+        assert np.array_equal(gi[1], block_ids[:10])
+    elif edge is _edge_neg_inf_thr:
+        assert np.all(gi[1] == -1) and gn[1] == 0
+    elif edge is _edge_bound:
+        gd = np.asarray(gd)
+        assert np.all((gi[:3, :5] >= 0) & (gi[:3, 5:] == -1))
+        assert np.all(gi[3] == -1) and gn[3] == 4 * 128
+
+
+# ---------------------------------------------------------------------------
 # banded-DTW wavefront (kernels/dtw_band.py)
 # ---------------------------------------------------------------------------
 

@@ -5,7 +5,9 @@ described, not attached, and refuses what the chip would refuse
 (unaligned blocks, layouts Mosaic cannot lower, too much VMEM).  The
 kernels are called with ``interpret=False`` directly, because the ops
 dispatch takes the jnp oracle on a CPU backend.  Shapes are the chip
-smoke's widths: n=256, w=16, C=512/1024, Q=16, k=10.
+smoke's widths: n=256, w=16, C=512/1024, Q=16, k=10; the gathered
+refine also at the one-query cell's Q=1, k=1, each over K=4 blocks of
+an index of 2^22 series.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
@@ -21,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.batch_l2 import batch_l2
 from repro.kernels.block_topk import block_topk
 from repro.kernels.dtw_band import dtw_band_panel
-from repro.kernels.fused_refine import fused_panel_topk
+from repro.kernels.fused_refine import fused_panel_topk, gathered_panel_topk
 from repro.kernels.isax_summarize import isax_summarize
 from repro.kernels.lb_scan import lb_scan
 
@@ -49,6 +51,16 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
+def _gathered_shapes(q, c, kb=4):
+    """q queries, each against kb blocks of an index of 2^22 series."""
+    b = (1 << 22) // c
+    return [((q, N), jnp.float32), ((q, W), jnp.float32),
+            ((b, c, N), jnp.float32), ((b, W, c), jnp.float32),
+            ((b, W, c), jnp.float32), ((b, c), jnp.int32),
+            ((q, kb), jnp.int32), ((q, kb), jnp.bool_), ((q,), jnp.float32),
+            ((q,), jnp.float32)]
+
+
 def _cases(c):
     """name -> (kernel with its static arguments, argument shapes)."""
     f32, i32 = jnp.float32, jnp.int32
@@ -72,6 +84,12 @@ def _cases(c):
             functools.partial(fused_panel_topk, k=K, n=N),
             [((Q, N), f32), ((Q, W), f32), ((c, N), f32), ((W, c), f32),
              ((W, c), f32), ((c,), i32), ((Q,), f32)]),
+        "gathered_panel_topk": (
+            functools.partial(gathered_panel_topk, k=K, n=N),
+            _gathered_shapes(Q, c)),
+        "gathered_panel_topk_q1": (
+            functools.partial(gathered_panel_topk, k=1, n=N),
+            _gathered_shapes(1, c)),
         "dtw_band_panel_shared": (
             functools.partial(dtw_band_panel, r=N // 10),
             [((Q, N), f32), ((c, N), f32)]),
@@ -89,3 +107,32 @@ def test_kernel_compiles_for_v5e(one_chip, name, capacity):
     compiled = jax.jit(functools.partial(fn, interpret=False)) \
         .lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_query_major_run_holds_no_gathered_copy(one_chip):
+    """``engine.run`` on a query-major ED plan, compiled for the v5e with
+    the Pallas kernels: no (Q, K, C, n) copy of the visited blocks exists
+    in the program.  The same plan without the LB filter still gathers,
+    which shows the check can see such a buffer."""
+    from repro.core import engine
+    from repro.core.index import BlockIndex
+    from repro.kernels import ops
+    c, b = 512, (1 << 22) // 512
+    f32, i32 = jnp.float32, jnp.int32
+    s = lambda shape, d=f32: jax.ShapeDtypeStruct(shape, d,
+                                                  sharding=one_chip)
+    index = BlockIndex(raw=s((b, c, N)), slo=s((b, W, c)), shi=s((b, W, c)),
+                       elo=s((W, b)), ehi=s((W, b)), ids=s((b, c), i32),
+                       n=N, w=W, card=256, capacity=c, n_real=b * c)
+    copy = (f"f32[{Q},4,{c},{N}]", f"f32[{Q * 4},{c},{N}]")
+
+    def hlo(metric):
+        plan = engine.QueryPlan(metric=metric, schedule="query_major", k=K)
+        with ops.kernel_mode("pallas"):
+            return engine.run.lower(index, s((Q, N)), plan).compile() \
+                .as_text()
+
+    fused = hlo(engine.ED())
+    assert "gathered_panel_topk" in fused
+    assert not any(t in fused for t in copy)
+    assert any(t in hlo(engine.ED(lb_filter=False)) for t in copy)
