@@ -8,8 +8,12 @@ Compiles, at the cell's own sizes and with the program's Pallas kernels
 since on a CPU backend its dispatch would pick the jnp oracles): the
 benchmark's generator chunk, the served path's programs (``core.build``
 and ``engine.run`` for ``hbm``; the block-LB kernel and the cached
-walk's refine step for ``disk``) and the reference's scan at both
-precisions.  Prints each program's compile seconds and device memory.
+walk's refine step for ``disk``; for a cell of several chips, the
+two-round protocol's ``distributed.build_sharded`` and
+``search_sharded`` jitted over a mesh of that many described chips,
+``N / chips`` series a chip) and the reference's scan of one chip's
+shard at both precisions.  Prints each program's compile seconds and
+memory on each device it runs on.
 What the chip's compiler refuses fails here, at no chip time; a compile
 that passes is not a run, and says nothing of results or times.
 """
@@ -34,18 +38,22 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(run.ROOT / "src"))
     import jax
     import jax.numpy as jnp
+    import numpy as np
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
+    from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
 
     import gen
     from repro import core
-    from repro.core import engine
+    from repro.core import distributed, engine
 
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
+    chips = c.cell["chips"]
     one = SingleDeviceSharding(topo.devices[0])
     cfg, traffic = c.cfg, c.traffic
     n_series, n = cfg["n_series"], cfg["length"]
+    shard_n = n_series // chips
     q_n, k = traffic["batch"], traffic["k"]
 
     def spec(shape, dtype=jnp.float32):
@@ -58,8 +66,11 @@ def main(argv=None) -> int:
         need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
         kernels = compiled.as_text().count("tpu_custom_call")
+        devices = {d for sh in jax.tree.leaves(compiled.input_shardings)
+                   for d in sh.device_set}
+        on = f" on each of {len(devices)} devices" if len(devices) > 1 else ""
         print(f"{name}: compiled in {time.perf_counter() - t0:.1f} s, "
-              f"{need / 2 ** 30:.3f} GiB, {kernels} Pallas call sites",
+              f"{need / 2 ** 30:.3f} GiB{on}, {kernels} Pallas call sites",
               flush=True)
 
     dataset = run.parts.load("datasets", cfg["dataset"])
@@ -69,7 +80,25 @@ def main(argv=None) -> int:
              rows=min(gen.CHUNK, n_series), length=n)
     kw = dict(w=cfg["w"], card=cfg["card"], capacity=cfg["capacity"])
     x = spec((n_series, n))
-    if cfg["placement"] == "hbm":
+    if chips > 1:
+        mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+
+        def on_mesh(shape, spec_, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype,
+                                        sharding=NamedSharding(mesh, spec_))
+
+        build = jax.jit(lambda a: distributed.build_sharded(a, mesh, **kw))
+        rows = on_mesh((n_series, n), P("data"))
+        compile_("distributed.build_sharded", build, rows)
+        shapes = jax.eval_shape(build, rows)
+        index = jax.tree.map(lambda s, p: on_mesh(s.shape, p, s.dtype),
+                             shapes,
+                             distributed.index_pspecs(mesh, like=shapes))
+        search = jax.jit(lambda i, q: distributed.search_sharded(
+            i, q, mesh, k=k, schedule="query_major"))
+        compile_("distributed.search_sharded", search, index,
+                 on_mesh((q_n, n), P()))
+    elif cfg["placement"] == "hbm":
         compile_("core.build", core.build, x, **kw)
         index = jax.eval_shape(lambda a: core.build(a, **kw), x)
         index = jax.tree.map(lambda s: spec(s.shape, s.dtype), index)
@@ -96,9 +125,10 @@ def main(argv=None) -> int:
                  spec((q_n,)), None, n=n, w=cfg["w"])
     tile = min(256, traffic["pool"])
     for prec in ("highest", "high"):
-        compile_(f"reference scan ({prec})", reference._scan_tile, x,
-                 spec((tile, n)), m=k + reference.MARGIN, precision=prec,
-                 chunk=min(1 << 17, n_series))
+        compile_(f"reference scan ({prec})", reference._scan_tile,
+                 spec((shard_n, n)), spec((tile, n)),
+                 m=k + reference.MARGIN, precision=prec,
+                 chunk=min(1 << 17, shard_n))
     return 0
 
 

@@ -13,14 +13,15 @@ configurations, ``Precision.HIGH``).  Each line of standard output is
 one JSON record; the last one gives the smallest reading of each
 number over the seeds.  The program's own readings, the lower ones,
 are the ``checks`` of the benchmark's runs.  The benchmark's own runs
-never run this.  ``--rehearse`` runs it on the CPU at the rehearsal
-size.
+never run this.  The collection is made as one row-contiguous shard on
+each of the cell's chips, as the benchmark's check makes it.
+``--rehearse`` runs it on the CPU at the rehearsal size, with as many
+CPU devices as the cell asks for chips.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 
 import numpy as np
@@ -29,13 +30,13 @@ import run
 
 
 def readings(c, seed: int) -> dict:
-    import jax.numpy as jnp
+    import jax
 
     import gen
-    x = gen.collection(c.cfg, seed)
-    pool = gen.queries(c.cfg, c.traffic, seed, c.traffic["pool"],
-                       lambda ids: jnp.take(x, jnp.asarray(ids), axis=0))
+    x = gen.collection_shards(c.cfg, seed, jax.devices()[:c.cell["chips"]])
     ref = run.parts.load("references", c.cfg["reference"])
+    pool = gen.queries(c.cfg, c.traffic, seed, c.traffic["pool"],
+                       lambda ids: ref.take(x, ids))
     k = c.traffic["k"]
     rows = np.arange(len(pool))
     dist, idx = ref.control_answers(x, pool, rows, k)
@@ -51,12 +52,17 @@ def main(argv=None) -> int:
                     help="comma-separated seeds, three or more")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
-    if args.rehearse:
-        os.environ["JAX_PLATFORMS"] = "cpu"
     c = run.load_cell(args.workload, args.rehearse)
+    chips = c.cell["chips"]
+    if args.rehearse:
+        run.rehearsal_env(chips)
     import jax
+    devices = jax.devices()
+    if len(devices) < chips:
+        return run.fail(f"the cell needs {chips} devices; JAX found "
+                        f"{len(devices)}")
     if not args.rehearse:
-        if jax.devices()[0].platform != "tpu":
+        if devices[0].platform != "tpu":
             return run.fail("control readings are taken on a TPU")
         run.enable_compile_cache()
     recs = []

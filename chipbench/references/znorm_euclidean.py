@@ -4,11 +4,15 @@ configurations, and its control.
 Imports nothing of the program.  Exact k-NN under z-normalised
 Euclidean distance, the semantics such a configuration states, by a
 full scan of the benchmark's own collection (remade from the seed after
-the window, so nothing the program built is read):
+the window, so nothing the program built is read).  The collection is a
+list of row-contiguous shards, each on its own device (one shard where
+the cell runs on one chip); a series' id is its row in the shards'
+concatenation.
 
 1. a device scan (``scan_topm``) in float32 at ``Precision.HIGHEST``
-   ranks every series for every distinct query of the window and keeps
-   the best ``k + MARGIN`` as candidates;
+   ranks every series of each shard for every distinct query of the
+   window, on that shard's device, and keeps the best ``k + MARGIN`` of
+   the shards' merged lists as candidates;
 2. on the host, every candidate and every reported id is z-normalised
    and measured again in float64 in the direct form sum((q - x)^2), and
    the candidates' float64 order gives the oracle's top k.  The oracle
@@ -35,6 +39,7 @@ come out not correct.
 from __future__ import annotations
 
 import functools
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -106,20 +111,31 @@ def _scan_tile(x, q, *, m: int, precision: str, chunk: int):
     return bd, bi
 
 
-def scan_topm(x: jax.Array, q: np.ndarray, m: int, *,
+def _starts(x: Sequence[jax.Array]) -> np.ndarray:
+    """The first id of each shard, and the number of series at the end."""
+    return np.cumsum([0] + [s.shape[0] for s in x])
+
+
+def scan_topm(x: Sequence[jax.Array], q: np.ndarray, m: int, *,
               precision: str = "highest", tile: int = 256,
               chunk: int = 1 << 17) -> tuple[np.ndarray, np.ndarray]:
-    """Top-m of every query by a float32 scan of the whole collection
-    ``x`` (N, n) on the device -> (squared distances, ids), (Q, m),
-    ascending by (distance, id).  Queries run in tiles of ``tile``, the
-    collection in chunks of ``chunk`` series; one program for all tiles.
-    ``precision`` is "highest" (float32 at ``Precision.HIGHEST``) or
-    "high" (three bf16 passes, ``_dot_high``)."""
-    n_series = x.shape[0]
-    chunk = min(chunk, n_series)
-    if n_series % chunk:
-        raise ValueError(f"{n_series} series do not cut into chunks of "
-                         f"{chunk}")
+    """Top-m of every query by a float32 scan of the whole collection,
+    held as the shards ``x`` ((N_j, n) each) -> (squared distances,
+    ids), (Q, m), ascending by (distance, id); m <= N.  Each shard is
+    scanned on its own device, all shards of a tile dispatched before
+    any is read, and keeps its best min(m, N_j) with ids offset by its
+    first row; the shards' lists merge by (distance, id), and the top-m
+    of the union is the merged top-m of the shards.  Queries run in
+    tiles of ``tile``, each shard in chunks of ``chunk`` series; one
+    program for all tiles of a shard.  ``precision`` is "highest"
+    (float32 at ``Precision.HIGHEST``) or "high" (three bf16 passes,
+    ``_dot_high``)."""
+    starts = _starts(x)
+    chunks = [min(chunk, s.shape[0]) for s in x]
+    for s, c in zip(x, chunks):
+        if s.shape[0] % c:
+            raise ValueError(f"{s.shape[0]} series do not cut into chunks "
+                             f"of {c}")
     tile = min(tile, len(q))
     out_d, out_i = [], []
     for s in range(0, len(q), tile):
@@ -127,10 +143,19 @@ def scan_topm(x: jax.Array, q: np.ndarray, m: int, *,
         pad = tile - len(part)
         if pad:
             part = np.concatenate([part, np.repeat(part[:1], pad, 0)])
-        d, i = _scan_tile(x, jnp.asarray(part), m=m, precision=precision,
-                          chunk=chunk)
-        out_d.append(np.asarray(d)[:tile - pad])
-        out_i.append(np.asarray(i)[:tile - pad])
+        part = jnp.asarray(part)
+        found = [_scan_tile(xs, part, m=min(m, xs.shape[0]),
+                            precision=precision, chunk=c)
+                 for xs, c in zip(x, chunks)]
+        d = np.concatenate([np.asarray(d) for d, _ in found], axis=1)
+        i = np.concatenate([np.asarray(i) + start
+                            for (_, i), start in zip(found, starts)], axis=1)
+        if len(x) > 1:     # one shard's list is in that order already
+            order = np.lexsort((i, d), axis=1)[:, :m]
+            d = np.take_along_axis(d, order, 1)
+            i = np.take_along_axis(i, order, 1)
+        out_d.append(d[:tile - pad])
+        out_i.append(i[:tile - pad])
     return np.concatenate(out_d), np.concatenate(out_i)
 
 
@@ -141,31 +166,48 @@ def znorm64(a: np.ndarray) -> np.ndarray:
     return (a - mu) / np.maximum(sd, 1e-8)
 
 
-def exact_d2(x: jax.Array, q: np.ndarray, ids: np.ndarray,
-             block: int = 1 << 16) -> np.ndarray:
+def take(x: Sequence[jax.Array], ids: np.ndarray, block: int = 1 << 16
+         ) -> np.ndarray:
+    """The series ``ids`` (R,), each in [0, N), on the host (R, n)
+    float32: each from the shard that holds it, in blocks of ``block``
+    ids."""
+    starts = _starts(x)
+    ids = np.asarray(ids)
+    owner = np.searchsorted(starts, ids, side="right") - 1
+    rows = np.zeros((len(ids), x[0].shape[1]), np.float32)
+    for j, (xs, start) in enumerate(zip(x, starts)):
+        at = np.flatnonzero(owner == j)
+        local = (ids[at] - start).astype(np.int32)
+        for s in range(0, len(at), block):
+            rows[at[s:s + block]] = np.asarray(
+                jnp.take(xs, jnp.asarray(local[s:s + block]), axis=0))
+    return rows
+
+
+def exact_d2(x: Sequence[jax.Array], q: np.ndarray, ids: np.ndarray
+             ) -> np.ndarray:
     """float64 squared distances of each query row ``q`` (R, n) to the
-    series ``ids`` (R, j) of ``x``, in the direct form; ids outside
-    [0, N) give NaN."""
-    n_series = x.shape[0]
+    series ``ids`` (R, j) of the sharded collection ``x``, in the direct
+    form; ids outside [0, N) give NaN."""
+    n_series = _starts(x)[-1]
     flat = ids.reshape(-1)
     ok = (flat >= 0) & (flat < n_series)
-    safe = np.where(ok, flat, 0).astype(np.int32)
-    rows = np.concatenate([
-        np.asarray(jnp.take(x, jnp.asarray(safe[s:s + block]), axis=0))
-        for s in range(0, len(safe), block)]) if len(safe) else \
-        np.zeros((0, x.shape[1]), np.float32)
+    rows = take(x, np.where(ok, flat, 0))
     qz = np.repeat(znorm64(q), ids.shape[1], axis=0)
     d = np.sum((znorm64(rows) - qz) ** 2, axis=1)
     return np.where(ok, d, np.nan).reshape(ids.shape)
 
 
 class Oracle:
-    """Exact k-NN of the distinct queries ``q`` (U, n) over ``x``.  A
-    query whose candidates cannot rule out a closer series outside them
-    is scanned again with eight times the candidates."""
+    """Exact k-NN of the distinct queries ``q`` (U, n) over the sharded
+    collection ``x``.  A query whose candidates cannot rule out a closer
+    series outside them is scanned again with eight times the
+    candidates; each shard's scan keeps at most all of its series
+    (``scan_topm``), so the candidates can grow to the whole
+    collection."""
 
-    def __init__(self, x: jax.Array, q: np.ndarray, k: int):
-        n_series = x.shape[0]
+    def __init__(self, x: Sequence[jax.Array], q: np.ndarray, k: int):
+        n_series = _starts(x)[-1]
         self.ids = np.zeros((len(q), k), np.int64)
         self.d2 = np.zeros((len(q), k))
         todo, m = np.arange(len(q)), k + MARGIN
@@ -182,16 +224,17 @@ class Oracle:
             todo, m = todo[open_], 8 * m
 
 
-def compare(x: jax.Array, pool: np.ndarray, rows: np.ndarray,
+def compare(x: Sequence[jax.Array], pool: np.ndarray, rows: np.ndarray,
             dist: np.ndarray, idx: np.ndarray, k: int,
             limits: dict) -> dict:
-    """Hold every answer to the oracle.  ``rows`` (A,) are the pool rows
-    the answers were asked for; ``dist``/``idx`` (A, k) the answers
-    (distances, not squared).  -> {name: {"value", "limit"}}, ``correct``
-    iff every value is within its limit."""
+    """Hold every answer to the oracle over the sharded collection
+    ``x``.  ``rows`` (A,) are the pool rows the answers were asked for;
+    ``dist``/``idx`` (A, k) the answers (distances, not squared).
+    -> {name: {"value", "limit"}}, ``correct`` iff every value is within
+    its limit."""
     uniq, inv = np.unique(rows, return_inverse=True)
     oracle = Oracle(x, pool[uniq], k)
-    n_series = x.shape[0]
+    n_series = _starts(x)[-1]
     idx = np.asarray(idx, np.int64)
     distinct = np.array([len(set(r.tolist())) == k for r in idx], bool)
     valid = distinct & np.all((idx >= 0) & (idx < n_series), axis=1)
@@ -211,8 +254,9 @@ def compare(x: jax.Array, pool: np.ndarray, rows: np.ndarray,
             for name, v in values.items()}
 
 
-def control_answers(x: jax.Array, pool: np.ndarray, rows: np.ndarray,
-                    k: int) -> tuple[np.ndarray, np.ndarray]:
+def control_answers(x: Sequence[jax.Array], pool: np.ndarray,
+                    rows: np.ndarray, k: int
+                    ) -> tuple[np.ndarray, np.ndarray]:
     """The control: the reference's scan in the program's place at
     ``Precision.HIGH`` (three bf16 passes, the step below the
     configuration's float32 at HIGHEST) -> (dist, idx) (A, k)."""

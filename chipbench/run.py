@@ -33,9 +33,11 @@ numbers compared are printed with their limits as the last lines of
 standard error and under ``checks``, the last key of the result line.
 
 It runs only on a TPU with the chips the cell asks for, and exits 2
-with no result otherwise.  ``--rehearse`` runs the same path on the CPU
-at the configuration's rehearsal size and prints counts and checks but
-no metric and no device.
+with no result otherwise.  The reference remakes the collection as one
+row-contiguous shard on each of those chips and scans it shard by shard.
+``--rehearse`` runs the same path on the CPU at the configuration's
+rehearsal size, with as many CPU devices as the cell asks for chips,
+and prints counts and checks but no metric and no device.
 """
 from __future__ import annotations
 
@@ -181,12 +183,13 @@ def closed_loop(served, traffic: dict, seconds: float) -> SimpleNamespace:
                            queries=sum(len(r.rows) for r in recs))
 
 
-def check(c: SimpleNamespace, seed: int, pool: np.ndarray, recs: list
-          ) -> dict:
+def check(c: SimpleNamespace, seed: int, pool: np.ndarray, recs: list,
+          devices: list) -> dict:
     """Hold the window's answers to the configuration's reference over
-    the collection of ``seed``, made again."""
+    the collection of ``seed``, made again as one shard on each of the
+    cell's ``devices``."""
     import gen
-    x = gen.collection(c.cfg, seed)
+    x = gen.collection_shards(c.cfg, seed, devices)
     rows = np.concatenate([r.rows for r in recs])
     return parts.load("references", c.cfg["reference"]).compare(
         x, pool, rows, np.concatenate([r.dist for r in recs]),
@@ -225,6 +228,17 @@ def enable_compile_cache() -> str:
     return path
 
 
+def rehearsal_env(chips: int) -> None:
+    """JAX on the CPU with ``chips`` devices, as a rehearsal of a cell
+    of that many chips runs.  Takes effect only where JAX has not yet
+    started in this process."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    count = "--xla_force_host_platform_device_count="
+    flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
+             if not f.startswith(count)]
+    os.environ["XLA_FLAGS"] = " ".join(flags + [f"{count}{chips}"])
+
+
 def fail(msg: str) -> int:
     print(f"chipbench: {msg}", file=sys.stderr)
     return 2
@@ -239,12 +253,13 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU, rehearsal size: counts and checks, no metric")
     args = ap.parse_args(argv)
-    if args.rehearse:
-        os.environ["JAX_PLATFORMS"] = "cpu"
     try:
         c = load_cell(args.workload, args.rehearse)
     except (OSError, KeyError, ValueError) as e:
         return fail(f"cannot read the cell's files: {e!r}")
+    chips = c.cell["chips"]
+    if args.rehearse:
+        rehearsal_env(chips)
     sys.path.insert(0, str(ROOT / "src"))
     try:
         import repro  # noqa: F401  the system under test
@@ -253,10 +268,11 @@ def main(argv=None) -> int:
     import jax
 
     devices = jax.devices()
-    chips = c.cell["chips"]
     if args.rehearse:
-        if devices[0].platform != "cpu":
-            return fail("a rehearsal runs on the CPU")
+        if devices[0].platform != "cpu" or len(devices) < chips:
+            return fail(f"a rehearsal runs on {chips} CPU device(s); JAX "
+                        f"found {len(devices)} {devices[0].platform} "
+                        f"device(s)")
         peaks = None
     else:
         if devices[0].platform != "tpu" or len(devices) < chips:
@@ -299,7 +315,7 @@ def main(argv=None) -> int:
         trace = trace_reduce.Trace(trace_reduce.read_xplane(xplanes[-1]))
         shutil.rmtree(trace_dir, ignore_errors=True)
 
-    checks = check(c, args.seed, pool, loop.recs) if loop.recs else {}
+    checks = check(c, args.seed, pool, loop.recs, used) if loop.recs else {}
     correct = bool(loop.recs) and loop.failed == 0 and passed(checks)
     counts = {name: sum(r.counters.get(name, 0) for r in loop.recs)
               / max(loop.queries, 1)
